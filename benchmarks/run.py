@@ -149,6 +149,8 @@ def main(argv=None):
                 f.write(md + "\n")
             print(f"roofline_report,{len(cells)},cells->results/roofline.md")
     except Exception:                                   # noqa: BLE001
+        failed += 1
+        print("roofline_report,ERROR,", file=sys.stdout)
         traceback.print_exc()
     return 1 if failed else 0
 

@@ -127,13 +127,10 @@ class TabularBackend(CostBackend):
 
 
 def cost_analysis_dict(cost) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions: older
-    releases return a one-element list of dicts (one per program), newer
-    ones the dict itself.  Every producer/consumer of cost records
-    (launch.dryrun, benchmarks.roofline_report, the calibration tests)
-    goes through this so the artifact schema stays a flat dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
+    """``Compiled.cost_analysis()`` as a plain dict.  Every producer/
+    consumer of cost records (launch.dryrun, benchmarks.roofline_report,
+    the calibration tests) goes through this so the artifact schema stays
+    a flat dict."""
     return dict(cost)
 
 

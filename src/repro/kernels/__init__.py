@@ -1,3 +1,8 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels, each with a jitted ``ops`` wrapper and a jnp ``ref``."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """Run Pallas in interpret mode on the CPU platform (tests, CI) and
+    nowhere else: on a TPU every kernel compiles to Mosaic."""
+    return jax.default_backend() == "cpu"

@@ -4,8 +4,8 @@ Accepts the model-layout tensors q:(B,Sq,H,D), k/v:(B,Skv,Hkv,D), pads the
 head_dim to a multiple of 128 (MXU lane width) and seq lens to the block
 size, transposes to head-major, runs the kernel, and undoes the padding.
 
-On CPU (this container) the kernel runs in interpret mode; on TPU it
-compiles to Mosaic. The flag is automatic from the backend.
+On the CPU platform the kernel runs in interpret mode; on TPU it
+compiles to Mosaic (``repro.kernels.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -14,11 +14,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
-
-
-def _is_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _pad_to(x, mult, axis):
@@ -31,13 +28,11 @@ def _pad_to(x, mult, axis):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_kv",
-                                             "softcap", "interpret"))
+                                             "softcap"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 512,
-                    block_kv: int = 512, softcap: float = 0.0,
-                    interpret: bool | None = None):
+                    block_kv: int = 512, softcap: float = 0.0):
     """Model layout in/out: q (B,Sq,H,D) -> (B,Sq,H,D)."""
-    if interpret is None:
-        interpret = _is_cpu()
+    interpret = interpret_mode()
     b, sq, h, d = q.shape
     skv = k.shape[1]
 

@@ -12,12 +12,17 @@ a warp-level gather over 16-token pages; the TPU-idiomatic analogue is:
 * Grid ``(B, Hkv, max_pages)``; each step attends one page.  All Q heads
   of one GQA group ride together as a ``(group, D)`` tile so the
   score matmul is ``(group × D) @ (D × page)`` on the MXU instead of a
-  per-head matvec.
+  per-head matvec.  q and out are viewed as ``(B, Hkv, group, D)`` so
+  that tile spans the block's last two dimensions whole, which Mosaic
+  accepts for any group size (1 for MHA).  A ``(1, group, D)`` block of
+  ``(B, Hq, D)`` is refused whenever ``group`` is neither a multiple of
+  8 nor ``Hq``.
 * Pages past ``ceil(context_len / page_size)`` are skipped with
   ``pl.when`` — requests only pay for the KV they actually hold, which is
   exactly the behavior TokenSim's block-granular memory manager models.
 
-Validated in interpret mode against ``ref.py``.
+Validated in interpret mode against ``ref.py``; ``tests/test_tpu_compile.py``
+compiles it for a TPU v5e.
 """
 from __future__ import annotations
 
@@ -48,7 +53,7 @@ def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(page_start < ctx_len)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                  # (group, D)
+        q = q_ref[0, 0].astype(jnp.float32)               # (group, D)
         k = k_ref[0, 0].astype(jnp.float32)               # (page, D)
         v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -72,8 +77,8 @@ def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(pi == max_pages - 1)
     def _finish():
         l = l_ref[:, 0]
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] /
+                       jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
 
 
 def paged_attention_fwd(q, k_pages, v_pages, block_tables, context_lens, *,
@@ -97,16 +102,16 @@ def paged_attention_fwd(q, k_pages, v_pages, block_tables, context_lens, *,
         grid=grid,
         in_specs=[
             # q: all heads of the kv group together
-            pl.BlockSpec((1, group, d),
-                         lambda b_, h_, pi, bt, cl: (b_, h_, 0)),
+            pl.BlockSpec((1, 1, group, d),
+                         lambda b_, h_, pi, bt, cl: (b_, h_, 0, 0)),
             # k/v: the physical page picked by the prefetched block table
             pl.BlockSpec((1, 1, page_size, d),
                          lambda b_, h_, pi, bt, cl: (h_, bt[b_, pi], 0, 0)),
             pl.BlockSpec((1, 1, page_size, d),
                          lambda b_, h_, pi, bt, cl: (h_, bt[b_, pi], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, group, d),
-                               lambda b_, h_, pi, bt, cl: (b_, h_, 0)),
+        out_specs=pl.BlockSpec((1, 1, group, d),
+                               lambda b_, h_, pi, bt, cl: (b_, h_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, d), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
@@ -114,11 +119,13 @@ def paged_attention_fwd(q, k_pages, v_pages, block_tables, context_lens, *,
         ],
     )
 
-    # (B, Hq, D) stays as-is; the (1, group, d) BlockSpec tiles the head
-    # axis by GQA groups (q heads of kv head h are contiguous: h*group..).
-    return pl.pallas_call(
+    # q heads of kv head h are contiguous (h*group..), so the reshape is
+    # free and groups the head axis by kv head.
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
-    )(block_tables, context_lens, q, k_pages, v_pages)
+    )(block_tables, context_lens, q.reshape(b, hkv, group, d), k_pages,
+      v_pages)
+    return out.reshape(b, hq, d)

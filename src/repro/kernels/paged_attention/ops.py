@@ -1,34 +1,25 @@
 """Jit'd public wrapper for paged decode attention.
 
 Natural serving layout in: q (B,H,D), pages (num_pages, page_size, Hkv, D)
-(token-major, what the serving engine appends into), block tables and
-context lens. The wrapper transposes pages to the kernel's head-major
-layout; on TPU that transpose is fused away by XLA when the cache is
-already stored head-major (the serving engine stores head-major on TPU).
+(token-major, the layout ``serving.paged_model.init_pages`` stores and
+appends into), block tables and context lens. The wrapper transposes the
+whole page pool to the kernel's head-major layout on every call.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.paged_attention.kernel import paged_attention_fwd
 
 
-def _is_cpu() -> bool:
-    return jax.default_backend() == "cpu"
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
-                    interpret: bool | None = None):
+@jax.jit
+def paged_attention(q, k_pages, v_pages, block_tables, context_lens):
     """q: (B,H,D); k/v_pages: (NP, page, Hkv, D) -> (B,H,D)."""
-    if interpret is None:
-        interpret = _is_cpu()
     kh = jnp.transpose(k_pages, (2, 0, 1, 3))      # (Hkv, NP, page, D)
     vh = jnp.transpose(v_pages, (2, 0, 1, 3))
     return paged_attention_fwd(q, kh, vh,
                                block_tables.astype(jnp.int32),
                                context_lens.astype(jnp.int32),
-                               interpret=interpret)
+                               interpret=interpret_mode())

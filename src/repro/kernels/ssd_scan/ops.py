@@ -10,20 +10,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.ssd_scan.kernel import ssd_scan_fwd
 
 
-def _is_cpu() -> bool:
-    return jax.default_backend() == "cpu"
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(xbar, dA_log, Bm, Cm, *, chunk: int = 256,
-             interpret: bool | None = None):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd_scan(xbar, dA_log, Bm, Cm, *, chunk: int = 256):
     """xbar (B,S,H,P); dA_log (B,S,H); Bm/Cm (B,S,G,N) ->
     (y (B,S,H,P) f32, final_state (B,H,N,P) f32)."""
-    if interpret is None:
-        interpret = _is_cpu()
+    interpret = interpret_mode()
     b, s, h, p = xbar.shape
     chunk = min(chunk, s) if s % chunk == 0 or s < chunk else chunk
     pad = (-s) % chunk

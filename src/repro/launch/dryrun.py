@@ -11,9 +11,12 @@ Usage:
       --mesh single --settings baseline --out results/dryrun
   python -m repro.launch.dryrun --all --mesh both
 """
-# The first two lines MUST run before any other import pulls in jax:
+# These lines MUST run before any other import pulls in jax. The devices
+# are virtual host devices: pin the CPU platform so that neither this
+# process nor a child that perf_hillclimb starts ever takes a TPU.
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"  # noqa: E402
+os.environ["JAX_PLATFORMS"] = "cpu"  # noqa: E402
 
 import argparse          # noqa: E402
 import dataclasses       # noqa: E402

@@ -1,21 +1,71 @@
 """Serving CLI: run the real paged-KV engine on a workload.
 
 Example:
-  PYTHONPATH=src python -m repro.launch.serve --arch llama2-7b --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b --smoke \
       --requests 50 --qps 0 --max-batch 8
+
+The functions below are the CLI's steps; ``chip_smoke.py`` drives the
+engine through them too.
 """
 from __future__ import annotations
 
 import argparse
 import json
+from typing import List
 
 import jax
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.metrics import Results
+from repro.core.request import Request
 from repro.core.workload import WorkloadSpec, generate
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo as zoo
 from repro.serving.engine import EngineConfig, ServingEngine
+
+
+def build_model(arch: str, *, smoke: bool = False, seed: int = 0):
+    """(model, params) with random weights from ``seed``."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    model = zoo.build(cfg)
+    return model, zoo.init_params(model, jax.random.key(seed))
+
+
+def make_requests(n: int, *, qps: float = 0.0, seed: int = 0,
+                  prompt_len: int = 0, output_len: int = 0) -> List[Request]:
+    """ShareGPT-shaped lengths capped at 96/32 tokens, or fixed lengths
+    where ``prompt_len`` > 0."""
+    if prompt_len:
+        wl = WorkloadSpec(num_requests=n, qps=qps, seed=seed,
+                          lengths="fixed", prompt_len=prompt_len,
+                          output_len=output_len or 16)
+    else:
+        wl = WorkloadSpec(num_requests=n, qps=qps, seed=seed,
+                          max_prompt_len=96, max_output_len=32)
+    return generate(wl)
+
+
+def make_engine(model, params, *, num_blocks: int = 512,
+                block_size: int = 16, max_batch: int = 8,
+                attn: str = "gather", seed: int = 0) -> ServingEngine:
+    ec = EngineConfig(num_blocks=num_blocks, block_size=block_size,
+                      max_batch=max_batch,
+                      max_pages_per_seq=num_blocks // max(4, max_batch),
+                      attn_path=attn, seed=seed)
+    return ServingEngine(model, params, ec)
+
+
+def submit(eng: ServingEngine, reqs: List[Request]) -> None:
+    """Queue every request at time 0 (closed batch)."""
+    for r in reqs:
+        r.arrival_time = 0.0
+        eng.add_request(r)
+
+
+def summarize(eng: ServingEngine, reqs: List[Request]) -> dict:
+    summary = Results(requests=reqs, sim_time=eng.clock).summary()
+    summary["iterations"] = len(eng.records)
+    return summary
 
 
 def main():
@@ -35,35 +85,17 @@ def main():
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    model = zoo.build(cfg)
-    params = zoo.init_params(model, jax.random.key(args.seed))
-
-    wl = WorkloadSpec(num_requests=args.requests, qps=args.qps,
-                      seed=args.seed)
-    if args.prompt_len:
-        wl = WorkloadSpec(num_requests=args.requests, qps=args.qps,
-                          seed=args.seed, lengths="fixed",
-                          prompt_len=args.prompt_len,
-                          output_len=args.output_len or 16)
-    else:
-        wl = WorkloadSpec(num_requests=args.requests, qps=args.qps,
-                          seed=args.seed, max_prompt_len=96,
-                          max_output_len=32)
-    reqs = generate(wl)
-    mp = args.num_blocks // max(4, args.max_batch)
-    ec = EngineConfig(num_blocks=args.num_blocks, block_size=args.block_size,
-                      max_batch=args.max_batch,
-                      max_pages_per_seq=mp, attn_path=args.attn,
-                      seed=args.seed)
-    eng = ServingEngine(model, params, ec)
-    for r in reqs:
-        r.arrival_time = 0.0
-        eng.add_request(r)
+    enable_compile_cache()
+    model, params = build_model(args.arch, smoke=args.smoke, seed=args.seed)
+    reqs = make_requests(args.requests, qps=args.qps, seed=args.seed,
+                         prompt_len=args.prompt_len,
+                         output_len=args.output_len)
+    eng = make_engine(model, params, num_blocks=args.num_blocks,
+                      block_size=args.block_size, max_batch=args.max_batch,
+                      attn=args.attn, seed=args.seed)
+    submit(eng, reqs)
     eng.run()
-    res = Results(requests=reqs, sim_time=eng.clock)
-    summary = res.summary()
-    summary["iterations"] = len(eng.records)
+    summary = summarize(eng, reqs)
     print(json.dumps(summary, indent=1))
     if args.out:
         with open(args.out, "w") as f:

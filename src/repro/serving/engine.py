@@ -289,22 +289,9 @@ class ServingEngine:
     def _run_decode(self, plan) -> None:
         reqs = plan.decode
         if self.paged:
-            bsz = self.ec.max_batch
-            tables = np.full((bsz, self.ec.max_pages_per_seq),
-                             self.trash_page, np.int32)
-            lens = np.zeros((bsz,), np.int32)
-            toks = np.zeros((bsz,), np.int32)
-            for i, r in enumerate(reqs):
-                bt = self.mem.block_table(r)
-                tables[i, :len(bt)] = bt
-                lens[i] = r.context_len - 1      # KV before this token
-                toks[i] = self._current_token(r)
-            self.pages = {**self.pages,
-                          "tables": jnp.asarray(tables),
-                          "len": jnp.asarray(lens)}
+            pages, toks = self.decode_batch(reqs)
             logits, self.pages = paged_model.paged_decode_step(
-                self.model, self.params, self.pages,
-                jnp.asarray(toks), self.ec.attn_path)
+                self.model, self.params, pages, toks, self.ec.attn_path)
             for i, r in enumerate(reqs):
                 self.tokens_by_req[r.id].append(self._sample(logits[i]))
         else:
@@ -320,6 +307,24 @@ class ServingEngine:
             for r in reqs:
                 self.tokens_by_req[r.id].append(
                     self._sample(logits[self.slot_of[r.id]]))
+
+    def decode_batch(self, reqs: List[Request]):
+        """Inputs of one paged decode step over ``reqs``: the page store
+        with their block tables and lengths, and their current tokens.
+        Rows past ``len(reqs)`` are padding that writes the trash page."""
+        bsz = self.ec.max_batch
+        tables = np.full((bsz, self.ec.max_pages_per_seq),
+                         self.trash_page, np.int32)
+        lens = np.zeros((bsz,), np.int32)
+        toks = np.zeros((bsz,), np.int32)
+        for i, r in enumerate(reqs):
+            bt = self.mem.block_table(r)
+            tables[i, :len(bt)] = bt
+            lens[i] = r.context_len - 1      # KV before this token
+            toks[i] = self._current_token(r)
+        pages = {**self.pages, "tables": jnp.asarray(tables),
+                 "len": jnp.asarray(lens)}
+        return pages, jnp.asarray(toks)
 
     def _current_token(self, req: Request) -> int:
         gen = self.tokens_by_req[req.id]

@@ -68,7 +68,8 @@ from repro.distributed.api import use_rules, make_rules
 from repro.models import model_zoo as zoo
 from repro.training.trainer import TrainConfig, make_train_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(data=4, model=2)
 cfg = get_smoke_config("qwen3-14b")
 model = zoo.build(cfg, tp=2)
 rules = make_rules(batch=("data",), heads="model", kv_heads="model",
