@@ -31,6 +31,14 @@ from repro.models import model_zoo as zoo
 from repro.serving import paged_model
 from repro.serving.sampling import sample_token
 
+#: host spans of one ``step()``, on the profiler's clock: the iteration
+#: (a step trace event numbered by the step's index) and, inside it, the
+#: plan with its bookkeeping, the inputs with the programs' launch, the
+#: sampling with its device-to-host syncs, and the records and emission
+SPANS = ITERATION, PLAN, PREPARE, SAMPLE, EMIT = (
+    "engine.iteration", "engine.plan", "engine.prepare", "engine.sample",
+    "engine.emit")
+
 
 @dataclass
 class EngineConfig:
@@ -51,7 +59,6 @@ class EngineConfig:
 class IterationRecord:
     mix: BatchMix
     wall: float
-    t_virtual: float
     batch_ids: Tuple[int, ...]
     kind: str                            # prefill | decode
 
@@ -134,9 +141,47 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     def step(self) -> Optional[IterationRecord]:
-        plan = self.sched.plan(self)
-        if plan.empty:
-            return None
+        with jax.profiler.StepTraceAnnotation(ITERATION,
+                                              step_num=len(self.records)):
+            with jax.profiler.TraceAnnotation(PLAN):
+                plan = self.sched.plan(self)
+                if plan.empty:
+                    return None
+                self._start_plan(plan)
+
+            t0 = time.perf_counter()
+            if plan.prefill:
+                self._run_prefill(plan)
+                kind = "prefill"
+                batch = tuple(r.id for r, _, _ in plan.prefill)
+            else:
+                self._run_decode(plan)
+                kind = "decode"
+                batch = tuple(r.id for r in plan.decode)
+            wall = time.perf_counter() - t0
+
+            with jax.profiler.TraceAnnotation(EMIT):
+                mix = BatchMix.from_batch(
+                    [(c, b) for _, c, b in plan.prefill],
+                    [r.context_len for r in plan.decode])
+                self.clock += wall
+                rec = IterationRecord(mix=mix, wall=wall, batch_ids=batch,
+                                      kind=kind)
+                self.records.append(rec)
+
+                now = self.clock
+                for req, chunk, _ in plan.prefill:
+                    req.prefill_done_len = max(req.cached_len,
+                                               req.prefill_done_len) + chunk
+                    if req.remaining_prefill == 0:
+                        self._emit(req, now)
+                for req in plan.decode:
+                    self._emit(req, now)
+            return rec
+
+    def _start_plan(self, plan) -> None:
+        """Admission and preemption bookkeeping of ``plan``, and the
+        decode rows' new slots in the block manager."""
         for req in plan.admitted:
             req.state = State.PREFILL if req.remaining_prefill else \
                 State.DECODE
@@ -158,35 +203,6 @@ class ServingEngine:
 
         for req in plan.decode:
             self.mem.append_tokens(req, 1)
-
-        t0 = time.perf_counter()
-        if plan.prefill:
-            self._run_prefill(plan)
-            kind = "prefill"
-            batch = tuple(r.id for r, _, _ in plan.prefill)
-        else:
-            self._run_decode(plan)
-            kind = "decode"
-            batch = tuple(r.id for r in plan.decode)
-        wall = time.perf_counter() - t0
-
-        mix = BatchMix.from_batch(
-            [(c, b) for _, c, b in plan.prefill],
-            [r.context_len for r in plan.decode])
-        self.clock += wall
-        rec = IterationRecord(mix=mix, wall=wall, t_virtual=self.clock,
-                              batch_ids=batch, kind=kind)
-        self.records.append(rec)
-
-        now = self.clock
-        for req, chunk, _ in plan.prefill:
-            req.prefill_done_len = max(req.cached_len,
-                                       req.prefill_done_len) + chunk
-            if req.remaining_prefill == 0:
-                self._emit(req, now)
-        for req in plan.decode:
-            self._emit(req, now)
-        return rec
 
     def run(self, max_steps: int = 1_000_000) -> None:
         steps = 0
@@ -228,35 +244,43 @@ class ServingEngine:
 
     def _run_prefill(self, plan) -> None:
         for req, chunk, ctx in plan.prefill:
-            seq = self._full_sequence(req)[:ctx + chunk]
-            plen = int(seq.shape[0])
-            spad = min(self._bucket(plen), self.max_ctx)
-            padded = np.zeros((1, spad), np.int32)
-            padded[0, :plen] = seq
-            toks = jnp.asarray(padded)
-            if self.paged:
-                last_logits, k, v = paged_model.prefill_collect(
-                    self.model, self.params, toks, plen)
-                table = np.full((self.ec.max_pages_per_seq,),
-                                self.trash_page, np.int32)
-                blocks = self.mem.block_table(req)
-                table[:len(blocks)] = blocks
-                self.pages = paged_model.scatter_prefill(
-                    self.model, self.pages, k, v,
-                    jnp.asarray(table), plen)
-            else:
-                slot = self.slot_of[req.id]
-                cache1 = zoo.init_cache(self.model, 1, self.max_ctx)
-                batch = {"tokens": toks}
-                if self.model.cfg.family in ("audio", "encdec"):
-                    batch["embeds"] = self._enc_embeds(req)[None]
-                logits, cache1 = self._prefill_slot_fn(
-                    self.model, self.params, batch, cache1)
-                last_logits = logits[0, plen - 1]
-                self._write_slot(slot, cache1, plen)
-            tok = self._sample(last_logits)
-            self.tokens_by_req[req.id].append(tok)
+            with jax.profiler.TraceAnnotation(PREPARE):
+                last_logits, plen = self._prefill_one(req, ctx + chunk)
+            with jax.profiler.TraceAnnotation(SAMPLE):
+                tok = self._sample(last_logits)
+                self.tokens_by_req[req.id].append(tok)
             self._slot_write_len(req, plen)
+
+    def _prefill_one(self, req: Request, upto: int):
+        """Prefill ``req``'s first ``upto`` tokens into its pages (or its
+        slot); returns the last position's logits and the length."""
+        seq = self._full_sequence(req)[:upto]
+        plen = int(seq.shape[0])
+        spad = min(self._bucket(plen), self.max_ctx)
+        padded = np.zeros((1, spad), np.int32)
+        padded[0, :plen] = seq
+        toks = jnp.asarray(padded)
+        if self.paged:
+            last_logits, k, v = paged_model.prefill_collect(
+                self.model, self.params, toks, plen)
+            table = np.full((self.ec.max_pages_per_seq,),
+                            self.trash_page, np.int32)
+            blocks = self.mem.block_table(req)
+            table[:len(blocks)] = blocks
+            self.pages = paged_model.scatter_prefill(
+                self.model, self.pages, k, v,
+                jnp.asarray(table), plen)
+        else:
+            slot = self.slot_of[req.id]
+            cache1 = zoo.init_cache(self.model, 1, self.max_ctx)
+            batch = {"tokens": toks}
+            if self.model.cfg.family in ("audio", "encdec"):
+                batch["embeds"] = self._enc_embeds(req)[None]
+            logits, cache1 = self._prefill_slot_fn(
+                self.model, self.params, batch, cache1)
+            last_logits = logits[0, plen - 1]
+            self._write_slot(slot, cache1, plen)
+        return last_logits, plen
 
     _prefill_slot_fn = staticmethod(
         jax.jit(zoo.prefill, static_argnums=0))
@@ -289,24 +313,26 @@ class ServingEngine:
     def _run_decode(self, plan) -> None:
         reqs = plan.decode
         if self.paged:
-            pages, toks = self.decode_batch(reqs)
-            logits, self.pages = paged_model.paged_decode_step(
-                self.model, self.params, pages, toks, self.ec.attn_path)
-            for i, r in enumerate(reqs):
-                self.tokens_by_req[r.id].append(self._sample(logits[i]))
+            with jax.profiler.TraceAnnotation(PREPARE):
+                pages, toks = self.decode_batch(reqs)
+                logits, self.pages = paged_model.paged_decode_step(
+                    self.model, self.params, pages, toks, self.ec.attn_path)
+            rows = range(len(reqs))
         else:
-            toks = np.zeros((self.ec.max_batch,), np.int32)
-            lens = np.array(self.cache["len"])
-            for r in reqs:
-                slot = self.slot_of[r.id]
-                toks[slot] = self._current_token(r)
-                lens[slot] = r.context_len - 1
-            self.cache["len"] = jnp.asarray(lens)
-            logits, self.cache = self._decode_slot_fn(
-                self.model, self.params, self.cache, jnp.asarray(toks))
-            for r in reqs:
-                self.tokens_by_req[r.id].append(
-                    self._sample(logits[self.slot_of[r.id]]))
+            with jax.profiler.TraceAnnotation(PREPARE):
+                toks = np.zeros((self.ec.max_batch,), np.int32)
+                lens = np.array(self.cache["len"])
+                for r in reqs:
+                    slot = self.slot_of[r.id]
+                    toks[slot] = self._current_token(r)
+                    lens[slot] = r.context_len - 1
+                self.cache["len"] = jnp.asarray(lens)
+                logits, self.cache = self._decode_slot_fn(
+                    self.model, self.params, self.cache, jnp.asarray(toks))
+            rows = [self.slot_of[r.id] for r in reqs]
+        with jax.profiler.TraceAnnotation(SAMPLE):
+            for r, i in zip(reqs, rows):
+                self.tokens_by_req[r.id].append(self._sample(logits[i]))
 
     def decode_batch(self, reqs: List[Request]):
         """Inputs of one paged decode step over ``reqs``: the page store
