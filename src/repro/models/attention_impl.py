@@ -12,7 +12,8 @@ Four paths, all numerically interchangeable (tests assert allclose):
                        attention FLOPs at long seq (matches what the Pallas
                        kernel does on TPU).
 * ``decode``         — one query token against a (possibly huge) KV cache,
-                       with fp32 online accumulation. GSPMD shards the KV
+                       grouped by kv head (K/V read in their own dtype,
+                       never repeated), fp32 accumulation. GSPMD shards the KV
                        sequence axis for ``long_500k`` (SP) and inserts the
                        partial-softmax collectives.
 
@@ -26,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _gqa_expand(k, n_q_heads):
@@ -204,22 +206,29 @@ def blocked_causal_attention(q, k, v, *, block_q=1024, block_kv=1024,
 def decode_attention(q, k_cache, v_cache, cache_len, *,
                      logit_softcap: float = 0.0):
     """q: (B,1,H,D); caches: (B,S,Hkv,D); cache_len: (B,) valid length
-    (the new token's kv must already be written at cache_len-1)."""
+    (the new token's kv must already be written at cache_len-1).
+
+    Grouped: the G = H // Hkv query heads of a kv group are contracted
+    against their kv head directly, so K/V are read once in their own
+    dtype and never repeated over G or widened to f32 in memory; scores,
+    softmax and accumulation are f32. ``HIGHEST`` keeps the products f32:
+    at the default precision a TPU's MXU takes f32 operands (the softmax
+    probabilities) as bf16."""
     b, _, h, d = q.shape
-    s = k_cache.shape[1]
-    kc = _gqa_expand(k_cache, h)
-    vc = _gqa_expand(v_cache, h)
+    s, hkv = k_cache.shape[1], k_cache.shape[2]
+    assert h % hkv == 0, (h, hkv)
+    qg = q.reshape(b, hkv, h // hkv, d)           # head j -> kv head j // G
     scale = d ** -0.5
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kc,
+    scores = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache, precision=HIGHEST,
                         preferred_element_type=jnp.float32) * scale
     if logit_softcap:
         scores = jnp.tanh(scores / logit_softcap) * logit_softcap
     valid = jnp.arange(s)[None, None, None, :] < cache_len[:, None, None, None]
     scores = jnp.where(valid, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(jnp.float32),
-                     vc.astype(jnp.float32))
-    return out.astype(q.dtype)
+    out = jnp.einsum("bhgk,bkhd->bhgd", probs, v_cache, precision=HIGHEST,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -88,3 +88,74 @@ def test_dispatch_paths():
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(o), np.asarray(outs[0]),
                                    rtol=2e-4, atol=2e-4)
+
+
+def _decode_expanded(q, k_cache, v_cache, cache_len, *, logit_softcap=0.0):
+    """The expand-and-widen formula, the grouped path's reference: K/V
+    repeated to every query head, V widened to f32."""
+    b, _, h, d = q.shape
+    s = k_cache.shape[1]
+    rep = h // k_cache.shape[2]
+    kc = jnp.repeat(k_cache, rep, axis=2)
+    vc = jnp.repeat(v_cache, rep, axis=2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kc,
+                        preferred_element_type=jnp.float32) * d ** -0.5
+    if logit_softcap:
+        scores = jnp.tanh(scores / logit_softcap) * logit_softcap
+    valid = jnp.arange(s)[None, None, None, :] < cache_len[:, None, None, None]
+    probs = jax.nn.softmax(jnp.where(valid, scores, -1e30), axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(jnp.float32),
+                     vc.astype(jnp.float32))
+    return out.astype(q.dtype)
+
+
+@pytest.mark.parametrize("q_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("softcap", [0.0, 30.0])
+@pytest.mark.parametrize("h,hkv", [(16, 8), (8, 1), (4, 4), (12, 4)])
+def test_decode_grouped_matches_expanded(h, hkv, softcap, q_dtype):
+    """Grouped decode over bf16 K/V == repeat-and-widen, query head j on
+    kv head j // (h // hkv), ragged lengths; q f32 or bf16."""
+    b, s, d = 4, 40, 32
+    q, k, v = rand_qkv(jax.random.key(7), b, 1, s, h, hkv, d)
+    q = (4.0 * q).astype(q_dtype)        # scores wide enough for the cap
+    k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    lens = jnp.array([1, 9, 33, 40], jnp.int32)
+    got = decode_attention(q, k, v, lens, logit_softcap=softcap)
+    want = _decode_expanded(q, k, v, lens, logit_softcap=softcap)
+    assert got.dtype == want.dtype == q_dtype
+    tol = 1e-5 if q_dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    # each query group reads its own kv head: swapping kv heads shows
+    if hkv > 1:
+        swapped = decode_attention(q, k[:, :, ::-1], v[:, :, ::-1], lens,
+                                   logit_softcap=softcap)
+        assert not np.allclose(np.asarray(swapped, np.float32),
+                               np.asarray(got, np.float32))
+
+
+def test_decode_grouped_keeps_kv_narrow():
+    """No f32 intermediate as large as K/V repeated to every query head:
+    the grouped path widens nothing of the cache's size."""
+    b, s, h, hkv, d = 4, 256, 16, 8, 128
+    q = jax.ShapeDtypeStruct((b, 1, h, d), jnp.float32)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16)
+    lens = jax.ShapeDtypeStruct((b,), jnp.int32)
+
+    def avals(jaxpr):                    # every equation's outputs, nested too
+        for e in jaxpr.eqns:
+            yield from ((str(e.primitive), v.aval) for v in e.outvars)
+            for p in e.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else (p,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from avals(sub)
+
+    closed = jax.make_jaxpr(decode_attention)(q, kv, kv, lens)
+    seen = list(avals(closed.jaxpr))
+    assert any(a.dtype == jnp.float32 and a.size == b * h * s
+               for _, a in seen)                 # the scores are there
+    big = [(p, a) for p, a in seen
+           if a.dtype == jnp.float32 and a.size >= b * s * h * d]
+    assert not big, big

@@ -11,7 +11,9 @@ while a module is imported would load the TPU library in every pytest
 worker, and only one process may hold it.
 """
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,12 +64,13 @@ def test_paged_attention_kernel_compiles(one_chip, arch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _decode_step_args(one_chip, model):
+def _decode_step_args(one_chip, model, batch=BATCH, num_pages=NUM_PAGES,
+                      max_pages=MAX_PAGES):
     params = jax.eval_shape(functools.partial(zoo.init_params, model),
                             jax.random.key(0))
     pages = jax.eval_shape(functools.partial(
-        paged_model.init_pages, model, NUM_PAGES, PAGE, BATCH, MAX_PAGES))
-    tokens = jax.ShapeDtypeStruct((BATCH,), jnp.int32)
+        paged_model.init_pages, model, num_pages, PAGE, batch, max_pages))
+    tokens = jax.ShapeDtypeStruct((batch,), jnp.int32)
     return _on(one_chip, (params, pages, tokens))
 
 
@@ -90,3 +93,25 @@ def test_paged_decode_step_compiles_full_width(one_chip, monkeypatch,
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16e9, used
+
+
+def test_gather_decode_step_keeps_kv_window_bf16(one_chip):
+    """The gather path at the offline benchmark cell's sizes (batch 32,
+    1,280 pages and the trash page, 128 pages a row): no f32 array as
+    large as the gathered K/V window, or that window repeated to every
+    query head, appears anywhere in the compiled step."""
+    batch, num_pages, max_pages = 32, 1280 + 1, 128
+    cfg = get_config("internlm2-1.8b")
+    model = zoo.build(cfg)
+    args = _decode_step_args(one_chip, model, batch, num_pages, max_pages)
+    compiled = paged_model.paged_decode_step.lower(
+        model, *args, "gather").compile()
+    window = batch * max_pages * PAGE * cfg.n_kv_heads * cfg.head_dim
+    sizes = {window, window * cfg.n_heads // cfg.n_kv_heads}
+    wide = sorted({dims for dims in re.findall(r"f32\[([\d,]+)\]",
+                                               compiled.as_text())
+                   if math.prod(map(int, dims.split(","))) in sizes})
+    assert not wide, wide
+    # 3,944,163,328 bytes with K/V repeated and widened (the same compile
+    # of the step before the grouped decode attention, JAX 0.9.0)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3_944_163_328
