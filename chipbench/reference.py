@@ -15,6 +15,10 @@ tokens and reads, at each position, the gap by which the served token's
 logit lies below the best. The control is the same pass with every
 weight matrix rounded to float8 (e4m3, one scale per matrix): the
 nearest step below the bfloat16 the configuration serves in.
+
+A configuration file names this module under ``"reference"``, and the
+harness reaches it through ``run.ARCH_API`` alone: ``program_sizes``,
+``gaps``, and the work counts of ``work.py``, re-exported.
 """
 from __future__ import annotations
 
@@ -24,6 +28,10 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench.work import decode_work, prefill_work
+
+__all__ = ["program_sizes", "gaps", "decode_work", "prefill_work"]
 
 HIGHEST = jax.lax.Precision.HIGHEST
 #: sequences are padded on the right to a multiple of this (causal
@@ -59,6 +67,32 @@ class Arch:
                     tied=bool(s["tie_word_embeddings"]),
                     experts=int(s.get("num_local_experts") or 0),
                     top_k=int(s.get("num_experts_per_tok") or 0))
+
+
+#: file keys whose value the program fixes in code and does not read from
+#: its ``ArchConfig``: (the value, where the program fixes it)
+FIXED = {
+    "rms_norm_eps": (1e-5, "the default eps of repro.models.layers."
+                           "norm_apply; model_zoo passes none"),
+}
+
+
+def program_sizes(cfg) -> dict:
+    """The program's ``ArchConfig`` read out under the file's keys; a
+    MoE's ``intermediate_size`` is its expert width."""
+    moe = cfg.moe
+    sizes = {"num_hidden_layers": cfg.num_layers, "hidden_size": cfg.d_model,
+             "num_attention_heads": cfg.n_heads,
+             "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+             "intermediate_size": moe.d_expert if moe else cfg.d_ff,
+             "vocab_size": cfg.vocab_size, "hidden_act": cfg.act,
+             "rope_theta": cfg.rope_theta,
+             "tie_word_embeddings": cfg.tie_embeddings,
+             "num_local_experts": moe.num_experts if moe else 0,
+             "num_experts_per_tok": moe.top_k if moe else 0,
+             "dtype": cfg.dtype}
+    sizes.update({k: v for k, (v, _) in FIXED.items()})
+    return sizes
 
 
 def _mm(x, w):
@@ -166,11 +200,14 @@ def _read(a: Arch, params, tokens, targets, control: bool):
     return out
 
 
-def gaps(a: Arch, params, prompt, served, *, control: bool = False) -> dict:
+def gaps(sizes: dict, params, prompt, served, *,
+         control: bool = False) -> dict:
     """Gaps (best logit minus the compared token's) at the positions
     that produced ``served``: the prompt's last, then each served token
     but the last. ``served`` gap: of the served tokens; ``control`` gap:
-    of the float8 pass's first choices."""
+    of the float8 pass's first choices. ``sizes``: the configuration
+    file."""
+    a = Arch.of(sizes)
     prompt, served = np.asarray(prompt), np.asarray(served)
     seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     n = len(seq)

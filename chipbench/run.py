@@ -6,12 +6,17 @@
 
 A cell of ``BENCHMARK.json`` names a configuration
 (``chipbench/configs/<config>.json``: the program's arch, its sizes, the
-engine's sizes, the output check's limit) and a traffic mix
+engine's sizes, the output check's limit, and under ``"reference"`` the
+module of its architecture) and a traffic mix
 (``chipbench/traffic/<mix>.json``). The run makes the weights from the
 seed, builds ``ServingEngine`` at the configured sizes, warms every
 program shape the mix can reach (set-up), serves the mix for a ramp and
 then the measured window on the wall clock, and checks a sample of what
-the window served against the float32 reference (``reference.py``).
+the window served against the configuration's float32 reference.
+
+The harness knows no architecture: the size check, the output check and
+the work counts of the roofline readers are the functions ``ARCH_API``
+of the module the configuration names.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
 traces the window with the JAX profiler and reports its per-layer
@@ -27,11 +32,13 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -47,10 +54,16 @@ ROOT = BENCH.parent
 CACHE = ROOT / ".jax_cache"
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
-from chipbench import client, devtrace, reference, traffic, weights, work  # noqa: E402,E501
+from chipbench import client, devtrace, traffic, weights, work  # noqa: E402
 
 #: at most this many requests go to the output check
 MAX_COMPARED = 16
+#: what a configuration's module exposes: ``program_sizes(cfg)``, the
+#: program's ``ArchConfig`` under the file's keys; ``gaps(sizes, params,
+#: prompt, served, *, control)``, the output check's logit gaps;
+#: ``decode_work(sizes, contexts)`` and ``prefill_work(sizes,
+#: prompt_len)``, (FLOPs, bytes) of a step
+ARCH_API = ("program_sizes", "gaps", "decode_work", "prefill_work")
 
 
 @dataclass
@@ -61,6 +74,40 @@ class Cell:
     traffic: traffic.Traffic
     end_to_end: list
     per_layer: list
+    #: the configuration's module (``ARCH_API``)
+    reference: object
+
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: Path):
+    """The Python file at ``path``, executed once per process (and listed
+    in ``sys.modules``, where a dataclass looks up its module)."""
+    name = "chipbench_file" + re.sub(r"\W", "_", str(path))
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = sys.modules[name] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_reference(sizes: dict, root: Path = ROOT, file: str = "the file"):
+    """The module that a configuration names under ``"reference"``, a
+    path under ``chipbench/``. No default: a file without the key, or a
+    module without one of ``ARCH_API``, stops the run."""
+    name = sizes.get("reference")
+    if not name:
+        raise SystemExit(f"{file} names no module under \"reference\" "
+                         f"(a path under chipbench/ with {ARCH_API})")
+    path = (root / name).resolve()
+    if (root / "chipbench").resolve() not in path.parents \
+            or not path.is_file():
+        raise SystemExit(f"{file}: \"reference\" {name!r} is no file "
+                         f"under chipbench/")
+    mod = load_module(path)
+    missing = [f for f in ARCH_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SystemExit(f"{name}, the reference of {file}, lacks "
+                         f"{missing}")
+    return mod
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
@@ -71,6 +118,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     cfg = next(c for c in bench["configs"] if c["name"] == w["config"])
     sizes = json.loads((root / cfg["file"]).read_text())
+    module = load_reference(sizes, root, cfg["file"])
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
     reported = {m["name"] for m in e2e}
@@ -80,27 +128,23 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     return Cell(name=name, chips=int(w["chips"]), sizes=sizes,
                 traffic=traffic.load(w["traffic"], root / "chipbench" /
                                      "traffic"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer, reference=module)
 
 
 # ---------------------------------------------------------------------------
 # Set-up
 # ---------------------------------------------------------------------------
-#: program ArchConfig field -> configuration file key
-_ARCH_KEYS = {"num_layers": "num_hidden_layers", "d_model": "hidden_size",
-              "n_heads": "num_attention_heads",
-              "n_kv_heads": "num_key_value_heads", "head_dim": "head_dim",
-              "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-              "tie_embeddings": "tie_word_embeddings", "dtype": "dtype"}
-
-
-def check_arch(cfg, sizes: dict) -> None:
-    """The program's configuration is the file's, or the run stops."""
-    got = {k: getattr(cfg, f) for f, k in _ARCH_KEYS.items()}
-    got["intermediate_size"] = cfg.moe.d_expert if cfg.moe else cfg.d_ff
-    got["num_local_experts"] = cfg.moe.num_experts if cfg.moe else 0
-    got["num_experts_per_tok"] = cfg.moe.top_k if cfg.moe else 0
-    bad = {k: (v, sizes[k]) for k, v in got.items() if v != sizes[k]}
+def check_arch(cfg, cell: Cell) -> None:
+    """The program's configuration is the file's, or the run stops: every
+    key the cell's ``program_sizes`` reads out of ``cfg`` holds the same
+    value in the file."""
+    got = cell.reference.program_sizes(cfg)
+    missing = sorted(set(got) - set(cell.sizes))
+    if missing:
+        raise SystemExit(f"the file of {cell.name} lacks {missing}, which "
+                         f"its reference compares with the program")
+    bad = {k: (v, cell.sizes[k]) for k, v in got.items()
+           if v != cell.sizes[k]}
     if bad:
         raise SystemExit(f"the program's {cfg.name} differs from its file "
                          f"(program, file): {bad}")
@@ -187,7 +231,7 @@ def build(cell: Cell, seed: int, cfg=None):
     from repro.models import model_zoo as zoo
     from repro.serving.engine import ServingEngine
     cfg = cfg or get_config(cell.sizes["arch"])
-    check_arch(cfg, cell.sizes)
+    check_arch(cfg, cell)
     model = zoo.build(cfg)
     params = weights.make(zoo.param_specs(model), seed)
     jax.block_until_ready(params)
@@ -260,7 +304,6 @@ def check(cell: Cell, params, tl, d, served, seed: int, *,
     Each holds the gap statistics, with the limits the configuration's
     ``limits`` name (None for the others), and the counts that must
     be 0."""
-    arch = reference.Arch.of(cell.sizes)
     limits = cell.sizes["limits"]
     picked = choose(tl, seed, cell.traffic.reference_tokens)
     wrong = sum(1 for s in tl.sent if s.req.finished
@@ -269,8 +312,8 @@ def check(cell: Cell, params, tl, d, served, seed: int, *,
     if control:
         gaps["control"] = []
     for s in picked:
-        g = reference.gaps(arch, params, d.prompts[s.req.id],
-                           served[s.req.id], control=control)
+        g = cell.reference.gaps(cell.sizes, params, d.prompts[s.req.id],
+                                served[s.req.id], control=control)
         for k, v in g.items():
             gaps[k].append(v)
     out = {}
@@ -290,15 +333,11 @@ def correct(compared: dict) -> bool:
 
 
 def per_layer(cell: Cell, tl, reduced, peak: dict) -> dict:
-    ctx = Context(timeline=tl, trace=reduced, sizes=cell.sizes, peak=peak)
+    ctx = Context(timeline=tl, trace=reduced, sizes=cell.sizes, peak=peak,
+                  reference=cell.reference)
     out = {}
     for m in cell.per_layer:
-        path = BENCH / "metrics" / f"{m['name']}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"chipbench_metric_{m['name']}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        v = mod.read(ctx)
+        v = load_module(BENCH / "metrics" / f"{m['name']}.py").read(ctx)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out
@@ -311,6 +350,8 @@ class Context:
     trace: Optional[devtrace.Reduced]
     sizes: dict
     peak: dict
+    #: the cell's module: its ``decode_work`` and ``prefill_work``
+    reference: object
 
 
 def read_trace(trace_dir: str) -> devtrace.Reduced:

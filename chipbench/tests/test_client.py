@@ -10,7 +10,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from chipbench import client, run, traffic
+from chipbench import client, devtrace, run, traffic, work
 
 ROOT = Path(__file__).resolve().parents[2]
 
@@ -163,14 +163,23 @@ def test_cli_refuses_without_a_tpu():
     assert not [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
 
 
+def copy_benchmark(root):
+    """The benchmark's configurations, with the modules they name, in a
+    checkout at ``root``; returns ``BENCHMARK.json`` as a dict."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    (root / "chipbench" / "traffic").mkdir(parents=True)
+    (root / "chipbench" / "configs").mkdir()
+    for c in bench["configs"]:
+        (root / c["file"]).write_text((ROOT / c["file"]).read_text())
+        module = json.loads((ROOT / c["file"]).read_text())["reference"]
+        (root / module).write_text((ROOT / module).read_text())
+    return bench
+
+
 def test_a_new_cell_is_found_by_name(tmp_path):
     """A later cell adds files and entries only: a traffic mix, and a
     workload that names it."""
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (tmp_path / "chipbench" / "traffic").mkdir(parents=True)
-    (tmp_path / "chipbench" / "configs").mkdir()
-    for c in bench["configs"]:
-        (tmp_path / c["file"]).write_text((ROOT / c["file"]).read_text())
+    bench = copy_benchmark(tmp_path)
     new = {"loop": "saturated", "ramp_s": 3, "drain_s": 0, "strata": 32,
            "prompt": {"median": 1500, "sigma": 0.5, "min": 256,
                       "max": 4096},
@@ -195,3 +204,112 @@ def test_a_new_cell_is_found_by_name(tmp_path):
                                                     "setup_s"}
     # per-layer metrics that list their cells do not reach a new one
     assert cell.per_layer == []
+
+
+#: the module of a configuration of another architecture: its sizes, gaps
+#: and counts are fixed numbers, so a reading shows whose they are
+STUB_ARCH = '''"""A stand-in architecture."""
+import numpy as np
+
+
+def program_sizes(cfg):
+    return {"hidden_size": cfg.width, "stub_kind": "latent"}
+
+
+def gaps(sizes, params, prompt, served, *, control=False):
+    out = {"served": np.full(len(served), sizes["stub_gap"])}
+    if control:
+        out["control"] = np.full(len(served), 2 * sizes["stub_gap"])
+    return out
+
+
+def decode_work(sizes, contexts):
+    return 1e9 * len(contexts), 4e6 * sum(contexts)
+
+
+def prefill_work(sizes, prompt_len):
+    return 3e9 * prompt_len, 1e6
+'''
+STUB_SIZES = dict(arch="stub-arch", reference="chipbench/stub_arch.py",
+                  hidden_size=7, stub_kind="latent", vocab_size=100,
+                  stub_gap=0.125, limits={"logit_gap": 0.2})
+
+
+def add_configuration(root, module=STUB_ARCH, sizes=STUB_SIZES):
+    """What a later PR adds for a new architecture: its configuration
+    file, the module the file names, and entries in ``BENCHMARK.json``
+    (a cell on the offline mix, listed by every metric that lists its
+    cells). Returns the cell's name."""
+    bench = copy_benchmark(root)
+    traffic_file = "chipbench/traffic/offline.json"
+    (root / traffic_file).write_text((ROOT / traffic_file).read_text())
+    (root / "chipbench" / "stub_arch.py").write_text(module)
+    file = "chipbench/configs/stub-arch.json"
+    (root / file).write_text(json.dumps(sizes))
+    bench["configs"].append({"name": "stub-arch", "source": "test",
+                             "file": file, "reduced": [], "why": "test"})
+    name = "stub-arch.offline"
+    bench["workloads"].append({"name": name, "config": "stub-arch",
+                               "traffic": "offline", "chips": 1,
+                               "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return name
+
+
+def test_a_new_configuration_brings_its_own_module(tmp_path):
+    """A configuration of another architecture adds files only; the size
+    check, the output check and both roofline readers reach its module
+    through the cell, with no file of ``chipbench/`` edited."""
+    name = add_configuration(tmp_path)
+    cell = run.load_cell(name, root=tmp_path)
+    assert Path(cell.reference.__file__) == \
+        (tmp_path / "chipbench" / "stub_arch.py").resolve()
+    run.check_arch(SimpleNamespace(name="stub", width=7), cell)
+    with pytest.raises(SystemExit, match="hidden_size"):
+        run.check_arch(SimpleNamespace(name="stub", width=8), cell)
+
+    clock = Clock()
+    eng = FakeEngine(clock)
+    t = mix(drain_s=60.0)
+    d = traffic.draw(t, 3, traffic.count_for(t, 4.0, 8), 100)
+    tl = client.run(eng, t, d, 4.0, clock=clock, sleep=clock.sleep)
+    compared = run.check(cell, None, tl, d, eng.tokens_by_req, 3,
+                         control=True)
+    assert compared["served"]["logit_gap"] == (0.125, 0.2)
+    assert run.correct(compared["served"])
+    assert not run.correct(compared["control"])
+
+    steps = tl.window_steps()
+    decode = [s for s in steps if s.kind == "decode"]
+    trace = devtrace.Reduced(
+        window_s=tl.window_s, busy_s=1.0, devices=1,
+        program_s={"jit_paged_decode_step": 0.5},
+        program_calls={"jit_paged_decode_step": len(decode)})
+    peak = work.peaks("TPU v5 lite")
+    layer = run.per_layer(cell, tl, trace, peak)
+    bound = sum(work.bound_seconds(1e9 * len(s.contexts),
+                                   4e6 * sum(s.contexts), peak)
+                for s in decode) / len(decode)
+    assert layer["decode_roofline"]["value"] == pytest.approx(
+        100.0 * bound / (0.5 / len(decode)))
+    flops = sum(1e9 * len(s.contexts) if s.kind == "decode"
+                else 3e9 * sum(s.prompts) for s in steps)
+    assert layer["mfu_pct"]["value"] == pytest.approx(
+        100.0 * flops / (tl.window_s * peak["flops_bf16"]))
+
+
+@pytest.mark.parametrize("missing", ["reference", "prefill_work"])
+def test_a_configuration_without_its_module_stops(tmp_path, missing):
+    """No default: a file that names no module, or a module without one
+    of ``run.ARCH_API``, stops the run with a message that names it."""
+    if missing == "reference":
+        sizes = {k: v for k, v in STUB_SIZES.items() if k != "reference"}
+        name = add_configuration(tmp_path, sizes=sizes)
+    else:
+        module = STUB_ARCH.split("\n\ndef prefill_work")[0]
+        name = add_configuration(tmp_path, module=module)
+    with pytest.raises(SystemExit, match=missing):
+        run.load_cell(name, root=tmp_path)

@@ -16,16 +16,18 @@ SEED = 2 ** 31 + 7
 #: the published configurations' structure at the program's smoke sizes
 SMOKE = {
     "internlm2-1.8b": dict(
+        reference="chipbench/reference.py",
         num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
         num_key_value_heads=2, head_dim=16, intermediate_size=192,
-        vocab_size=384, rope_theta=10000.0, rms_norm_eps=1e-5,
-        tie_word_embeddings=False, num_local_experts=0,
+        vocab_size=384, hidden_act="silu", rope_theta=10000.0,
+        rms_norm_eps=1e-5, tie_word_embeddings=False, num_local_experts=0,
         num_experts_per_tok=0, dtype="bfloat16"),
     "granite-moe-1b-a400m": dict(
+        reference="chipbench/reference.py",
         num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
         num_key_value_heads=2, head_dim=16, intermediate_size=64,
-        vocab_size=384, rope_theta=10000.0, rms_norm_eps=1e-5,
-        tie_word_embeddings=True, num_local_experts=4,
+        vocab_size=384, hidden_act="silu", rope_theta=10000.0,
+        rms_norm_eps=1e-5, tie_word_embeddings=True, num_local_experts=4,
         num_experts_per_tok=2, dtype="bfloat16"),
 }
 #: limits for these smoke sizes, between readings on the CPU: widest gap
@@ -55,7 +57,8 @@ def smoke_cell(arch, mix):
     sizes = dict(SMOKE[arch], arch=arch, limits={"logit_gap": LIMIT[arch]},
                  engine={"max_batch": 8, "block_size": 16,
                          "num_blocks": 128, "max_batched_tokens": 2048})
-    return dataclasses.replace(bench, sizes=sizes, traffic=t)
+    return dataclasses.replace(bench, sizes=sizes, traffic=t,
+                               reference=run.load_reference(sizes))
 
 
 def rehearse(arch, mix, seconds=1.0, patch=None):
@@ -68,6 +71,26 @@ def rehearse(arch, mix, seconds=1.0, patch=None):
     tl, d, served = run.serve(cell, engine, SEED, seconds,
                               compiles=compiles)
     return cell, params, tl, d, served
+
+
+@pytest.mark.parametrize("arch", list(SMOKE))
+def test_size_check_passes_on_the_smoke_structures(arch):
+    run.check_arch(get_smoke_config(arch), smoke_cell(arch, "offline"))
+
+
+@pytest.mark.parametrize("key,value", [
+    ("rms_norm_eps", 1e-6),               # the program fixes 1e-5
+    ("hidden_act", "gelu"),               # the program's is silu
+    ("hidden_act", None),                 # a key the file lacks
+], ids=["rms_norm_eps", "hidden_act", "missing"])
+def test_size_check_stops_on_a_changed_or_missing_key(key, value):
+    cell = smoke_cell("internlm2-1.8b", "offline")
+    sizes = dict(cell.sizes, **{key: value})
+    if value is None:
+        del sizes[key]
+    with pytest.raises(SystemExit, match=key):
+        run.check_arch(get_smoke_config("internlm2-1.8b"),
+                       dataclasses.replace(cell, sizes=sizes))
 
 
 @pytest.mark.parametrize("arch,mix", CELLS)

@@ -4,7 +4,7 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import work
+from chipbench import client, devtrace, run, work
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
@@ -105,3 +105,34 @@ def test_unknown_device_kind_raises():
         work.peaks("TPU v99")
     assert work.peaks("TPU v5 lite")["flops_bf16"] == 197e12
     assert work.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+
+
+def test_readers_through_the_cells_module_equal_the_counts():
+    """``decode_roofline`` and ``mfu_pct`` reach the counts through the
+    cell's module; on a synthetic timeline they read exactly what
+    ``work`` gives when called directly."""
+    cell = run.load_cell("internlm2-1.8b.offline")
+    assert cell.sizes == INTERNLM
+    steps = [client.Step(start=10.0 + 0.25 * i, end=10.2 + 0.25 * i,
+                         wall=0.15, kind=kind, contexts=ctx, prompts=p)
+             for i, (kind, ctx, p) in enumerate([
+                 ("prefill", (), (110, 1024)),
+                 ("decode", (37, 600, 1024, 5), ()),
+                 ("decode", (38, 601, 1025, 6), ()),
+                 ("prefill", (), (7,))])]
+    tl = client.Timeline(sent=[], steps=steps, open=10.0, close=11.0)
+    trace = devtrace.Reduced(window_s=1.0, busy_s=0.6, devices=1,
+                             program_s={"jit_paged_decode_step": 0.3},
+                             program_calls={"jit_paged_decode_step": 2})
+    peak = work.peaks("TPU v5 lite")
+    layer = run.per_layer(cell, tl, trace, peak)
+    decode = steps[1:3]
+    bound = sum(work.bound_seconds(*work.decode_work(INTERNLM, s.contexts),
+                                   peak) for s in decode) / len(decode)
+    assert layer["decode_roofline"]["value"] == 100.0 * bound / (0.3 / 2)
+    flops = sum(work.decode_work(INTERNLM, s.contexts)[0]
+                if s.kind == "decode" else
+                sum(work.prefill_work(INTERNLM, p)[0] for p in s.prompts)
+                for s in steps)
+    assert layer["mfu_pct"]["value"] == 100.0 * flops / (1.0 * peak[
+        "flops_bf16"])
