@@ -14,13 +14,15 @@ from repro.configs.base import (ArchConfig, MoEConfig, SSMConfig, ShapeSpec,
 from repro.configs import (chameleon_34b, zamba2_2_7b, stablelm_3b, qwen3_14b,
                            qwen2_0_5b, internlm2_1_8b, granite_moe_1b,
                            granite_moe_3b, mamba2_130m, whisper_base,
-                           llama2_7b, opt_13b)
+                           llama2_7b, opt_13b, moonlight_16b_a3b)
 
 _MODULES = [chameleon_34b, zamba2_2_7b, stablelm_3b, qwen3_14b, qwen2_0_5b,
             internlm2_1_8b, granite_moe_1b, granite_moe_3b, mamba2_130m,
-            whisper_base, llama2_7b, opt_13b]
+            whisper_base, llama2_7b, opt_13b, moonlight_16b_a3b]
 
 CONFIGS: Dict[str, ArchConfig] = {m.CONFIG.name: m.CONFIG for m in _MODULES}
+#: one chip's share of a deployment, served by the chip benchmark
+CONFIGS[moonlight_16b_a3b.EP8.name] = moonlight_16b_a3b.EP8
 SMOKE_CONFIGS: Dict[str, ArchConfig] = {
     m.CONFIG.name: m.SMOKE for m in _MODULES}
 

@@ -31,15 +31,32 @@ FAMILIES = (DENSE, MOE, SSM, HYBRID, ENCDEC, VLM, AUDIO)
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts sub-config (granite-style token-choice top-k)."""
+    """Mixture-of-experts sub-config: token-choice top-k over
+    ``num_experts`` router outputs (granite's softmax top-k by default;
+    DeepSeek-V3's sigmoid scores with a correction bias where
+    ``scoring="sigmoid"``)."""
 
-    num_experts: int
+    num_experts: int                   # the router's width
     top_k: int
     d_expert: int                      # per-expert FFN hidden size
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     router_z_coef: float = 1e-3
     jitter_eps: float = 0.0
+    # softmax | sigmoid: DeepSeek-V3's ``noaux_tc``, the top-k chosen on
+    # the scores plus a learned per-expert correction bias and weighted by
+    # the scores alone, normalised over the k
+    scoring: str = "softmax"
+    routed_scale: float = 1.0          # routed_scaling_factor
+    n_shared: int = 0                  # shared experts of width d_expert
+    # experts held here, ``held_start`` .. ``held_start + n_held - 1`` of
+    # the router's ``num_experts`` (expert parallelism); 0: all of them
+    n_held: int = 0
+    held_start: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -77,6 +94,15 @@ class ArchConfig:
 
     # Attention details.
     head_dim: int = 0                 # 0 -> d_model // n_heads
+    # Latent attention (MLA, DeepSeek-V2/V3) where kv_lora_rank > 0: a
+    # kv_lora_rank-wide latent and one qk_rope_head_dim-wide rope key
+    # shared by all heads are cached; each head's qk_nope_head_dim key
+    # and v_head_dim value are expanded from the latent. head_dim is
+    # then qk_nope_head_dim + qk_rope_head_dim.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     qk_norm: bool = False
     qkv_bias: bool = False
     attn_logit_softcap: float = 0.0
@@ -91,6 +117,8 @@ class ArchConfig:
 
     # Sub-family configs.
     moe: Optional[MoEConfig] = None
+    # MoE: the first ``first_k_dense`` layers are dense SwiGLU of width d_ff
+    first_k_dense: int = 0
     ssm: Optional[SSMConfig] = None
 
     # Hybrid (zamba2-style): `attn_period` SSM layers share one attention
@@ -124,8 +152,20 @@ class ArchConfig:
     def __post_init__(self):
         if self.family not in FAMILIES:
             raise ValueError(f"unknown family {self.family!r}")
+        if self.mla:
+            object.__setattr__(self, "head_dim", self.qk_nope_head_dim
+                               + self.qk_rope_head_dim)
         if self.head_dim == 0 and self.n_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one token's cached MLA latent: latent and rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def q_dim(self) -> int:
@@ -152,6 +192,8 @@ class ArchConfig:
             embed = 0  # encoder input is an embedding stub
 
         def attn_params(n_heads, n_kv, head_dim, bias):
+            if self.mla:
+                return self.mla_param_count()
             p = d * n_heads * head_dim + 2 * d * n_kv * head_dim \
                 + n_heads * head_dim * d
             if bias:
@@ -169,11 +211,15 @@ class ArchConfig:
             layers = self.num_layers * (per + 2 * d)
         elif self.family == MOE:
             m = self.moe
-            per = attn_params(self.n_heads, self.n_kv_heads, self.head_dim,
-                              self.qkv_bias)
-            per += m.num_experts * self.d_model * m.d_expert * (3 if gated else 2)
+            attn = attn_params(self.n_heads, self.n_kv_heads, self.head_dim,
+                               self.qkv_bias)
+            per = attn + m.held * mlp_params(m.d_expert, gated)
             per += d * m.num_experts  # router
-            layers = self.num_layers * (per + 2 * d)
+            per += m.num_experts if m.scoring == "sigmoid" else 0  # bias
+            per += mlp_params(m.n_shared * m.d_expert, gated)
+            dense = attn + mlp_params(self.d_ff, gated) + 2 * d
+            layers = (self.num_layers - self.first_k_dense) * (per + 2 * d) \
+                + self.first_k_dense * dense
         elif self.family in (SSM, HYBRID):
             s = self.ssm
             d_in = s.d_inner(d)
@@ -202,14 +248,27 @@ class ArchConfig:
             layers = enc + dec
         return embed + unembed + layers + d  # + final norm
 
+    def mla_param_count(self) -> int:
+        """One MLA block: q, the latent's down projection with its norm,
+        the latent's expansion to each head's no-rope key and value, and
+        the output projection."""
+        d, h = self.d_model, self.n_heads
+        return (d * h * self.head_dim + d * self.latent_dim
+                + self.kv_lora_rank
+                + self.kv_lora_rank * h * (self.qk_nope_head_dim
+                                           + self.v_head_dim)
+                + h * self.v_head_dim * d)
+
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: top-k experts only)."""
+        """Parameters touched per token (MoE: top-k of the held experts
+        only)."""
         if self.family != MOE:
             return self.param_count()
         m = self.moe
         gated = self.act == "silu"
-        inactive = self.num_layers * (m.num_experts - m.top_k) * \
-            self.d_model * m.d_expert * (3 if gated else 2)
+        inactive = (self.num_layers - self.first_k_dense) \
+            * (m.held - min(m.top_k, m.held)) \
+            * self.d_model * m.d_expert * (3 if gated else 2)
         return self.param_count() - inactive
 
 

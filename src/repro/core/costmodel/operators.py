@@ -307,9 +307,13 @@ class OperatorGraph:
 # ---------------------------------------------------------------------------
 def kv_bytes_per_token(cfg: ArchConfig, dtype_bytes: int = 2,
                        tp: int = 1) -> float:
-    """Bytes of KV cache one context token occupies (per device shard)."""
+    """Bytes of KV cache one context token occupies (per device shard).
+    MLA caches one latent and one rope key a layer, which every head
+    reads: not divided over ``tp``."""
     if cfg.family == SSM:
         return 0.0                        # constant state, no per-token KV
+    if cfg.mla:
+        return float(cfg.num_layers * cfg.latent_dim * dtype_bytes)
     if cfg.family == HYBRID:
         napp = cfg.num_layers // cfg.attn_period if cfg.attn_period else 0
         return 2.0 * napp * cfg.n_kv_heads * cfg.head_dim * dtype_bytes / tp

@@ -204,9 +204,11 @@ def blocked_causal_attention(q, k, v, *, block_q=1024, block_kv=1024,
 # Decode
 # ---------------------------------------------------------------------------
 def decode_attention(q, k_cache, v_cache, cache_len, *,
-                     logit_softcap: float = 0.0):
-    """q: (B,1,H,D); caches: (B,S,Hkv,D); cache_len: (B,) valid length
-    (the new token's kv must already be written at cache_len-1).
+                     logit_softcap: float = 0.0, scale=None):
+    """q: (B,1,H,D); k_cache: (B,S,Hkv,D); v_cache: (B,S,Hkv,Dv), Dv <= D
+    (absorbed MLA reads a value narrower than its key); cache_len: (B,)
+    valid length (the new token's kv must already be written at
+    cache_len-1). ``scale``: of the scores (default D ** -0.5).
 
     Grouped: the G = H // Hkv query heads of a kv group are contracted
     against their kv head directly, so K/V are read once in their own
@@ -218,7 +220,8 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
     s, hkv = k_cache.shape[1], k_cache.shape[2]
     assert h % hkv == 0, (h, hkv)
     qg = q.reshape(b, hkv, h // hkv, d)           # head j -> kv head j // G
-    scale = d ** -0.5
+    if scale is None:
+        scale = d ** -0.5
     scores = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache, precision=HIGHEST,
                         preferred_element_type=jnp.float32) * scale
     if logit_softcap:
@@ -228,7 +231,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgk,bkhd->bhgd", probs, v_cache, precision=HIGHEST,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, 1, h, d).astype(q.dtype)
+    return out.reshape(b, 1, h, v_cache.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

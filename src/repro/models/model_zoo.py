@@ -16,6 +16,7 @@ Entry points
 
 Cache layouts (leading L axis is scanned):
   dense/moe/vlm: {k,v: (L,B,Smax,Hkv_phys,hd), len: (B,)}
+  latent (MLA):  {latent: (L,B,Smax,kv_lora_rank+qk_rope_head_dim), len}
   ssm:           {conv: (L,B,W-1,C), ssd: (L,B,H,N,P) f32, len: (B,)}
   hybrid:        ssm states for all layers + {k,v: (Napp,B,Smax,H,hd)}
   audio(enc-dec):{k,v: (Ld,...), xk,xv: (Ld,B,Senc,H,hd), len}
@@ -141,6 +142,9 @@ def _attn_full(p, x, model: Model, positions, *, causal: bool, kv_x=None,
                return_kv: bool = False):
     """Full-sequence attention (train / prefill / encoder)."""
     cfg, st = model.cfg, model.settings
+    if cfg.mla:
+        return _mla_full(p, x, model, positions, causal=causal,
+                         return_kv=return_kv)
     q = _q_proj(p, x, model, positions)
     k, v = _kv_proj(p, kv_x if kv_x is not None else x, model, positions)
     impl = st.resolve_attn(q.shape[1])
@@ -159,9 +163,13 @@ def _attn_decode(p, x_t, model: Model, k_cache, v_cache, cache_len,
 
     x_t: (B,1,d).  For self-attn the new token's K/V is written at
     ``cache_len`` first; for cross-attn the cache is read-only.
-    Returns (out (B,1,d), k_cache, v_cache).
+    Returns (out (B,1,d), k_cache, v_cache). MLA: ``k_cache`` is the
+    latent cache (B,Smax,R+rope) and ``v_cache`` None.
     """
     cfg = model.cfg
+    if cfg.mla:
+        out, k_cache = _mla_attn_decode(p, x_t, model, k_cache, cache_len)
+        return out, k_cache, v_cache
     bsz = x_t.shape[0]
     positions = cache_len[:, None]                      # (B,1)
     q = _q_proj(p, x_t, model, positions)
@@ -182,22 +190,138 @@ def _attn_decode(p, x_t, model: Model, k_cache, v_cache, cache_len,
 
 
 # ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2/V3) without q-LoRA
+# ---------------------------------------------------------------------------
+#: eps of the latent's RMSNorm (DeepSeek-V3's ``kv_a_layernorm`` default)
+MLA_KV_NORM_EPS = 1e-6
+
+
+def _mla_init(key, model: Model):
+    cfg = model.cfg
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dt = model.param_dtype
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], d, h * cfg.head_dim, dt),
+        "wkv_a": dense_init(ks[1], d, cfg.latent_dim, dt),
+        "kv_norm": {"scale": jnp.ones((r,), dt)},
+        "wkv_b": dense_init(ks[2], r,
+                            h * (cfg.qk_nope_head_dim + cfg.v_head_dim), dt),
+        "wo": dense_init(ks[3], h * cfg.v_head_dim, d, dt,
+                         scale=(h * cfg.v_head_dim) ** -0.5),
+    }
+
+
+def _mla_q(p, x, model: Model, positions):
+    """(q_nope (B,S,H,nope), q_pe (B,S,H,rope) with rope applied)."""
+    cfg = model.cfg
+    b, s, _ = x.shape
+    q = dense_apply(p["wq"], x, model.compute_dtype).reshape(
+        b, s, cfg.n_heads, cfg.head_dim)
+    nope = cfg.qk_nope_head_dim
+    return q[..., :nope], apply_rope(q[..., nope:], positions, cfg.rope_theta)
+
+
+def _mla_latent(p, x, model: Model, positions):
+    """What a token caches, (B,S,kv_lora_rank + qk_rope_head_dim): the
+    RMS-normed latent, then the rope key that every head shares."""
+    cfg = model.cfg
+    kv = dense_apply(p["wkv_a"], x, model.compute_dtype)
+    c = norm_apply(p["kv_norm"], kv[..., :cfg.kv_lora_rank], "rmsnorm",
+                   eps=MLA_KV_NORM_EPS)
+    if positions.ndim == 1:
+        positions = positions[None]
+    k_pe = apply_rope(kv[..., None, cfg.kv_lora_rank:], positions,
+                      cfg.rope_theta)[..., 0, :]
+    return jnp.concatenate([c, k_pe], axis=-1)
+
+
+def _mla_out(p, ctx, model: Model):
+    """ctx: (B,S,H,v_head_dim) -> (B,S,d)."""
+    b, s = ctx.shape[:2]
+    return dense_apply(p["wo"], ctx.reshape(b, s, -1), model.compute_dtype)
+
+
+def _mla_full(p, x, model: Model, positions, *, causal: bool,
+              return_kv: bool = False):
+    """Full-sequence MLA with each head's key and value expanded from the
+    latent; the latent is what it returns for the cache."""
+    cfg, st = model.cfg, model.settings
+    b, s, _ = x.shape
+    h, nope, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    q_nope, q_pe = _mla_q(p, x, model, positions)
+    lat = _mla_latent(p, x, model, positions)
+    kv = dense_apply(p["wkv_b"], lat[..., :cfg.kv_lora_rank],
+                     model.compute_dtype).reshape(b, s, h, nope + dv)
+    k_pe = jnp.broadcast_to(lat[:, :, None, cfg.kv_lora_rank:],
+                            (b, s, h, cfg.qk_rope_head_dim))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    # every impl takes V as wide as Q and K: pad V with zeros, drop them
+    v = jnp.pad(kv[..., nope:], ((0, 0),) * 3 + ((0, cfg.head_dim - dv),))
+    ctx = attend(q, k, v, causal=causal, impl=st.resolve_attn(s),
+                 block_q=st.attn_block_q, block_kv=st.attn_block_kv)
+    out = _mla_out(p, ctx[..., :dv], model)
+    return (out, lat) if return_kv else out
+
+
+def mla_decode_attention(p, x_t, model: Model, latent_seq, valid):
+    """Absorbed MLA for one token a row against its latent cache.
+
+    x_t: (B,1,d) (normed); latent_seq: (B,S,kv_lora_rank+rope) with the
+    new token's latent written; valid: (B,) keys to attend. The query's
+    no-rope part is taken through ``wkv_b``'s key half into the latent's
+    space and joined to its rope part: one latent kv head read by all H
+    query heads (G = H), scaled by head_dim ** -0.5 of the published
+    192-wide key. The values are the latent's first kv_lora_rank
+    columns, taken through ``wkv_b``'s value half per head after."""
+    cfg = model.cfg
+    cd = model.compute_dtype
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    with jax.named_scope("mla_attention"):
+        positions = (valid - 1)[:, None]
+        q_nope, q_pe = _mla_q(p, x_t, model, positions)
+        w = p["wkv_b"]["w"].astype(cd).reshape(r, cfg.n_heads, -1)
+        q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w[..., :nope])
+        q_abs = jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], axis=-1)
+        k = latent_seq[:, :, None, :]                   # (B,S,1,R+rope)
+        ctx = decode_attention(q_abs, k, k[..., :r], valid,
+                               scale=cfg.head_dim ** -0.5)
+        ctx = jnp.einsum("bqhr,rhv->bqhv", ctx, w[..., nope:])
+    return _mla_out(p, ctx, model)
+
+
+def _mla_attn_decode(p, x_t, model: Model, lat_cache, cache_len):
+    """Contiguous cache: write the token's latent at ``cache_len``, then
+    attend. Returns (out (B,1,d), lat_cache)."""
+    positions = cache_len[:, None]
+    new = _mla_latent(p, x_t, model, positions)[:, 0]
+    lat_cache = lat_cache.at[jnp.arange(x_t.shape[0]), cache_len].set(
+        new.astype(lat_cache.dtype))
+    out = mla_decode_attention(p, x_t, model, lat_cache, cache_len + 1)
+    return out, lat_cache
+
+
+# ---------------------------------------------------------------------------
 # Transformer blocks (dense / moe)
 # ---------------------------------------------------------------------------
-def _block_init(key, model: Model, *, cross: bool = False):
+def _block_init(key, model: Model, *, cross: bool = False,
+                dense: bool = False):
+    """``dense``: a leading dense layer of a MoE model (SwiGLU of d_ff)."""
     cfg = model.cfg
     dt = model.param_dtype
     ks = jax.random.split(key, 4)
     p = {"ln1": norm_init(cfg.d_model, cfg.norm, dt),
-         "attn": _attn_init(ks[0], model),
+         "attn": (_mla_init if cfg.mla else _attn_init)(ks[0], model),
          "ln2": norm_init(cfg.d_model, cfg.norm, dt)}
     if cross:
         p["ln_x"] = norm_init(cfg.d_model, cfg.norm, dt)
         p["xattn"] = _attn_init(ks[1], model, cross=True)
-    if cfg.family == MOE:
+    if cfg.family == MOE and not dense:
         m = cfg.moe
         p["moe"] = moe_init(ks[2], cfg.d_model, model.plan.n_experts,
-                            m.d_expert, cfg.act, dt)
+                            m.d_expert, cfg.act, dt, n_held=m.n_held,
+                            n_shared=m.n_shared, scoring=m.scoring)
     elif cfg.d_ff:
         p["mlp"] = mlp_init(ks[2], cfg.d_model, cfg.d_ff, cfg.act, dt,
                             bias=cfg.mlp_bias or cfg.family in (AUDIO, ENCDEC)
@@ -209,9 +333,12 @@ def _ffn_apply(p, x, model: Model):
     """MLP or MoE second half-block. Returns (y, aux)."""
     cfg, st = model.cfg, model.settings
     if "moe" in p:
-        y, aux = moe_apply(p["moe"], x, top_k=cfg.moe.top_k,
+        m = cfg.moe
+        y, aux = moe_apply(p["moe"], x, top_k=m.top_k,
                            n_experts_logical=model.plan.n_experts_logical,
-                           impl=st.moe_impl, compute_dtype=model.compute_dtype)
+                           impl=st.moe_impl, compute_dtype=model.compute_dtype,
+                           scoring=m.scoring, routed_scale=m.routed_scale,
+                           held_start=m.held_start, act=cfg.act)
         return y, aux
     y = mlp_apply(p["mlp"], x, cfg.act, model.compute_dtype)
     return y, None
@@ -271,8 +398,13 @@ def init_params(model: Model, key) -> Dict[str, Any]:
 
     if cfg.family in (DENSE, MOE, VLM):
         params["embed"] = embed_init(ks[0], plan.vocab, cfg.d_model, dt)
+        if cfg.first_k_dense:
+            params["dense_layers"] = _stack_init(
+                lambda k: _block_init(k, model, dense=True), ks[4],
+                cfg.first_k_dense)
         params["layers"] = _stack_init(
-            lambda k: _block_init(k, model), ks[1], cfg.num_layers)
+            lambda k: _block_init(k, model), ks[1],
+            cfg.num_layers - cfg.first_k_dense)
         params["final_norm"] = norm_init(cfg.d_model, cfg.norm, dt)
         if not cfg.tie_embeddings:
             params["unembed"] = embed_init(ks[2], plan.vocab, cfg.d_model, dt)
@@ -395,6 +527,23 @@ def _scan_or_unroll(model: Model, body, carry, xs):
     return carry, ys
 
 
+def _layer_runs(model: Model, params):
+    """(stacked layer params, first layer, end) of each run of one kind
+    of layer: a MoE model's leading dense layers, then the rest."""
+    k = model.cfg.first_k_dense
+    runs = [(params["layers"], k, model.cfg.num_layers)]
+    if k:
+        runs.insert(0, (params["dense_layers"], 0, k))
+    return runs
+
+
+def _join(parts):
+    """The runs' per-layer outputs as one stack (leading layer axis)."""
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
+
+
 def _collect_aux(auxs) -> Dict[str, jnp.ndarray]:
     if auxs is None:
         return {}
@@ -440,7 +589,8 @@ def forward(model: Model, params, batch) -> Tuple[jnp.ndarray, Dict]:
             x, aux, _ = _block_apply(lp, x, model, positions, causal=True)
             return x, aux
 
-        x, auxs = _scan_blocks(params["layers"], x, body, model)
+        for layers, _, _ in _layer_runs(model, params):
+            x, auxs = _scan_blocks(layers, x, body, model)    # last: MoE
         return _lm_head(model, params, x), _collect_aux(auxs)
 
     if cfg.family == SSM:
@@ -583,7 +733,9 @@ def _cache_struct(model: Model, batch: int, max_len: int) -> Dict[str, Any]:
     cd = model.compute_dtype
     hd = cfg.head_dim
     out: Dict[str, Tuple[tuple, Any]] = {"len": ((batch,), jnp.int32)}
-    if cfg.family in (DENSE, MOE, VLM):
+    if cfg.mla:
+        out["latent"] = ((cfg.num_layers, batch, max_len, cfg.latent_dim), cd)
+    elif cfg.family in (DENSE, MOE, VLM):
         kv = (cfg.num_layers, batch, max_len, plan.n_kv, hd)
         out["k"] = (kv, cd)
         out["v"] = (kv, cd)
@@ -647,7 +799,17 @@ def prefill(model: Model, params, batch, cache, prompt_lens=None):
                                     return_kv=True)
             return x, kv
 
-        x, kvs = _scan_blocks(params["layers"], x, body, model)
+        kvs = []
+        for layers, _, _ in _layer_runs(model, params):
+            x, kv = _scan_blocks(layers, x, body, model)
+            kvs.append(kv)
+        kvs = _join(kvs)
+        if cfg.mla:                                 # (L,B,S,R+rope)
+            cache["latent"] = jax.lax.dynamic_update_slice(
+                cache["latent"], kvs.astype(cache["latent"].dtype),
+                (0, 0, 0, 0))
+            cache["len"] = prompt_lens
+            return _lm_head(model, params, x), cache
         k_new, v_new = kvs                          # (L,B,S,Hkv,hd)
         cache["k"] = jax.lax.dynamic_update_slice(
             cache["k"], k_new.astype(cache["k"].dtype), (0, 0, 0, 0, 0))
@@ -774,9 +936,21 @@ def decode_step(model: Model, params, cache, tokens):
             x_t, k, v = _block_decode(lp, x_t, model, k, v, cache_len)
             return x_t, (k, v)
 
-        x, kv = _scan_or_unroll(model, body, x, (params["layers"],
-                                              cache["k"], cache["v"]))
-        cache["k"], cache["v"] = kv
+        # MLA: the latent cache in K's place, no V
+        stacks = (cache["latent"], None) if cfg.mla else \
+            (cache["k"], cache["v"])
+        runs = _layer_runs(model, params)
+        kvs = []
+        for layers, lo, hi in runs:
+            cut = stacks if len(runs) == 1 else tuple(
+                None if a is None else a[lo:hi] for a in stacks)
+            x, kv = _scan_or_unroll(model, body, x, (layers, *cut))
+            kvs.append(kv)
+        kv = _join(kvs)
+        if cfg.mla:
+            cache["latent"] = kv[0]
+        else:
+            cache["k"], cache["v"] = kv
         cache["len"] = cache_len + 1
         logits = _lm_head(model, params, x)[:, 0]
         return logits, cache

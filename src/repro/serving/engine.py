@@ -72,6 +72,8 @@ class ServingEngine:
         #: tenant-aware queue ordering (repro.core.tenancy.qos); None=FIFO
         self.discipline = discipline
         self.paged = paged_model.supports_paged(model)
+        if self.paged:
+            paged_model.check_attn_path(model, ec.attn_path)
 
         mc = MemoryConfig(num_blocks=ec.num_blocks,
                           block_size=ec.block_size,
@@ -261,15 +263,14 @@ class ServingEngine:
         padded[0, :plen] = seq
         toks = jnp.asarray(padded)
         if self.paged:
-            last_logits, k, v = paged_model.prefill_collect(
+            last_logits, kv = paged_model.prefill_collect(
                 self.model, self.params, toks, plen)
             table = np.full((self.ec.max_pages_per_seq,),
                             self.trash_page, np.int32)
             blocks = self.mem.block_table(req)
             table[:len(blocks)] = blocks
             self.pages = paged_model.scatter_prefill(
-                self.model, self.pages, k, v,
-                jnp.asarray(table), plen)
+                self.model, self.pages, kv, jnp.asarray(table), plen)
         else:
             slot = self.slot_of[req.id]
             cache1 = zoo.init_cache(self.model, 1, self.max_ctx)

@@ -115,3 +115,28 @@ def test_gather_decode_step_keeps_kv_window_bf16(one_chip):
     # 3,944,163,328 bytes with K/V repeated and widened (the same compile
     # of the step before the grouped decode attention, JAX 0.9.0)
     assert compiled.memory_analysis().temp_size_in_bytes < 3_944_163_328
+
+
+def test_mla_decode_step_fits_the_moonlight_cell(one_chip):
+    """Moonlight-16B-A3B's one-chip share at the offline benchmark cell's
+    sizes (batch 128, 8,192 latent pages and the trash page, 128 pages a
+    row): absorbed decode over the latent pages fits one v5e beside its
+    float32 weights and keeps the gathered latent window bf16."""
+    batch, num_pages, max_pages = 128, 8192 + 1, 128
+    cfg = get_config("moonlight-16b-a3b-ep8")
+    model = zoo.build(cfg)
+    args = _decode_step_args(one_chip, model, batch, num_pages, max_pages)
+    compiled = paged_model.paged_decode_step.lower(
+        model, *args, "gather").compile()
+    window = batch * max_pages * PAGE
+    sizes = {window * cfg.latent_dim, window * cfg.kv_lora_rank}
+    wide = sorted({dims for dims in re.findall(r"f32\[([\d,]+)\]",
+                                               compiled.as_text())
+                   if math.prod(map(int, dims.split(","))) in sizes})
+    assert not wide, wide
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    # 13.5e9 bytes with JAX 0.9.0: 6.2e9 of f32 weights, the 1.36e9 pool
+    # in, out and in the scratch, a 3.1e9 bf16 copy of the weights
+    assert used < 16e9, used
