@@ -29,12 +29,13 @@ from repro.core.request import Request, State
 from repro.core.sched.local import make_local_scheduler
 from repro.models import model_zoo as zoo
 from repro.serving import paged_model
-from repro.serving.sampling import sample_token
+from repro.serving.sampling import sample_tokens
 
 #: host spans of one ``step()``, on the profiler's clock: the iteration
 #: (a step trace event numbered by the step's index) and, inside it, the
 #: plan with its bookkeeping, the inputs with the programs' launch, the
-#: sampling with its device-to-host syncs, and the records and emission
+#: sampling with its device-to-host transfer (``rows``: the rows whose
+#: tokens it keeps), and the records and emission
 SPANS = ITERATION, PLAN, PREPARE, SAMPLE, EMIT = (
     "engine.iteration", "engine.plan", "engine.prepare", "engine.sample",
     "engine.emit")
@@ -248,9 +249,9 @@ class ServingEngine:
         for req, chunk, ctx in plan.prefill:
             with jax.profiler.TraceAnnotation(PREPARE):
                 last_logits, plen = self._prefill_one(req, ctx + chunk)
-            with jax.profiler.TraceAnnotation(SAMPLE):
-                tok = self._sample(last_logits)
-                self.tokens_by_req[req.id].append(tok)
+            with jax.profiler.TraceAnnotation(SAMPLE, rows=1):
+                sampled = self._sample(last_logits[None])
+                self.tokens_by_req[req.id].append(int(sampled[0]))
             self._slot_write_len(req, plen)
 
     def _prefill_one(self, req: Request, upto: int):
@@ -331,9 +332,10 @@ class ServingEngine:
                 logits, self.cache = self._decode_slot_fn(
                     self.model, self.params, self.cache, jnp.asarray(toks))
             rows = [self.slot_of[r.id] for r in reqs]
-        with jax.profiler.TraceAnnotation(SAMPLE):
+        with jax.profiler.TraceAnnotation(SAMPLE, rows=len(reqs)):
+            sampled = self._sample(logits)
             for r, i in zip(reqs, rows):
-                self.tokens_by_req[r.id].append(self._sample(logits[i]))
+                self.tokens_by_req[r.id].append(int(sampled[i]))
 
     def decode_batch(self, reqs: List[Request]):
         """Inputs of one paged decode step over ``reqs``: the page store
@@ -359,8 +361,14 @@ class ServingEngine:
             return int(gen[-1])
         return int(self.prompt_tokens[req.id][-1])
 
-    def _sample(self, logits) -> int:
-        self._key, sub = jax.random.split(self._key)
-        return int(sample_token(logits, sub, greedy=self.ec.greedy,
-                                temperature=self.ec.temperature,
-                                vocab_logical=self.model.plan.vocab_logical))
+    def _sample(self, logits) -> np.ndarray:
+        """The tokens of every row of ``logits`` (B, V) on the host: one
+        program and one transfer. Greedy takes no key; under a
+        temperature the key is split once a call."""
+        key = self._key
+        if not self.ec.greedy:
+            self._key, key = jax.random.split(self._key)
+        return np.asarray(sample_tokens(
+            logits, key, greedy=self.ec.greedy,
+            temperature=self.ec.temperature,
+            vocab_logical=self.model.plan.vocab_logical))

@@ -107,19 +107,21 @@ def test_prefill_then_paged_decode_agree_with_the_reference():
     model, params = _params(SMOKE)
     engine = ServingEngine(model, params, EngineConfig(
         num_blocks=48, block_size=4, max_batch=3, max_pages_per_seq=12))
-    seen = []
+    seen = []                            # each sampling call's rows
     sample = engine._sample
 
     class Emitted(list):
-        """The tokens of one request, each with the logits it came from."""
+        """The tokens of one request, each with the logits it came from:
+        the rows of a call go, in order, to the tokens appended after
+        it (the padded rows of a decode step to none)."""
         logits: list
 
         def append(self, tok):
-            self.logits.append(seen[-1])
+            self.logits.append(seen[-1].pop(0))
             super().append(tok)
 
     def record(logits):
-        seen.append(np.asarray(logits[:SMOKE.vocab_size]))
+        seen.append(list(np.asarray(logits[:, :SMOKE.vocab_size])))
         return sample(logits)
 
     engine._sample = record

@@ -1,4 +1,4 @@
-"""Compile the serving path's kernel and decode step for a TPU v5e.
+"""Compile the serving path's kernel, decode step and sampler for a TPU v5e.
 
 Nothing runs here: the TPU compiler installed with JAX compiles for a
 described chip, so whatever Mosaic or XLA would refuse on the device (a
@@ -24,6 +24,7 @@ from repro.configs import get_config
 from repro.kernels.paged_attention.kernel import paged_attention_fwd
 from repro.models import model_zoo as zoo
 from repro.serving import paged_model
+from repro.serving.sampling import sample_tokens
 
 #: the pool and batch that `repro.launch.serve.make_engine` builds by default
 BATCH, PAGE, NUM_PAGES, MAX_PAGES = 8, 16, 512 + 1, 512 // 8
@@ -140,3 +141,26 @@ def test_mla_decode_step_fits_the_moonlight_cell(one_chip):
     # 13.5e9 bytes with JAX 0.9.0: 6.2e9 of f32 weights, the 1.36e9 pool
     # in, out and in the scratch, a 3.1e9 bf16 copy of the weights
     assert used < 16e9, used
+
+
+@pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "temperature"])
+@pytest.mark.parametrize("arch,batch", [
+    ("internlm2-1.8b", 32), ("moonlight-16b-a3b-ep8", 128)],
+    ids=["internlm2", "moonlight"])
+def test_batched_sampler_compiles_at_the_cells_logits(one_chip, arch, batch,
+                                                      greedy):
+    """The sampler over one decode step's whole logits in each offline
+    cell: internlm2's 32 rows of 92,672 (92,544 logical), moonlight's 128
+    of 163,840, as the decode step returns them."""
+    model = zoo.build(get_config(arch))
+    params, pages, tokens = _decode_step_args(one_chip, model, batch,
+                                              num_pages=65, max_pages=4)
+    logits, _ = paged_model.paged_decode_step.eval_shape(
+        model, params, pages, tokens, "gather")
+    assert logits.shape == (batch, model.plan.vocab)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = sample_tokens.lower(
+        *_on(one_chip, (logits, key)), greedy=greedy, temperature=0.7,
+        vocab_logical=model.plan.vocab_logical).compile()
+    out = compiled.out_info
+    assert (out.shape, out.dtype) == ((batch,), jnp.int32)
